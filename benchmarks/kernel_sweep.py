@@ -1,9 +1,10 @@
 """Pallas kernel block-shape sweep (structural VMEM/roofline reasoning).
 
-No real TPU: per the brief, the "profile" here is structural — per config
-we report the VMEM working set each program instance claims, its alignment
-to the 8×128 vreg grid, and the analytic HBM↔VMEM traffic; interpret-mode
-wall time is shown only as a correctness-execution proxy.  The chosen
+The "profile" here is structural — per config we report the VMEM working
+set each program instance claims, its alignment to the 8×128 vreg grid,
+and the analytic HBM↔VMEM traffic.  The wall time is one host-clocked
+call including compilation; on the CPU the kernels are interpreted, so
+there it is only a correctness-execution proxy.  The chosen
 defaults (marked *) are the ones whose working set fits comfortably under
 half of v5e's ~16 MiB VMEM (double-buffering headroom) with fully-aligned
 lanes.
@@ -77,12 +78,12 @@ def sweep_transpose(lanes: int = 1 << 15):
     rng = np.random.default_rng(2)
     v = jnp.asarray(rng.integers(0, 2**32, size=lanes, dtype=np.uint32))
     print("# kernel_sweep/transpose: name,us_per_call,derived(vmem_kb)")
-    for bb in (32, 128, 256, 512):
+    for bb in (1024, 2048, 4096, 8192):
         vmem = 2 * bb * 32 * 4
         t0 = time.perf_counter()
         h2v_pallas(v, block_b=bb)
         us = (time.perf_counter() - t0) * 1e6
-        star = "*" if bb == 256 else " "
+        star = "*" if bb == 1024 else " "
         print(f"transpose/bb{bb}{star},{us:.0f},{vmem/1024:.0f}")
 
 
@@ -116,7 +117,8 @@ def main():
     sweep_bitserial()
     sweep_transpose()
     sweep_bank("addition", 8)
-    print("# note: wall times are interpret-mode proxies; selection is by "
+    print("# note: wall times include compilation (interpreted on the CPU); "
+          "selection is by "
           "VMEM working set + 128-lane alignment (see module docstring)")
 
 

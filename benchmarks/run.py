@@ -17,6 +17,8 @@ import argparse
 import sys
 import time
 
+from repro import compile_cache
+
 from . import bank_scaling as B
 from . import chip_scaling as C
 from . import fault_sweep as F
@@ -66,6 +68,7 @@ def main() -> None:
                    help="tiny shapes; used by scripts/ci.sh for the apps "
                         "bit-exactness gate")
     args = p.parse_args()
+    compile_cache.configure()
 
     t0 = time.time()
     names = [args.table] if args.table else list(TABLES)

@@ -20,23 +20,27 @@ query oracle.
 
 from __future__ import annotations
 
-from typing import Dict
+from typing import Callable, Dict, List, Tuple
 
 import numpy as np
 
+from repro.core.bank import BbopInstr
 from repro.core.isa import SimdramDevice
 
 from .runtime import (QueueBuilder, gather, n_parallel_units,
                       resolve_device, shard_slices, verify)
 
 
-def run(
-    n_rows: int = 8192,
-    device: SimdramDevice | None = None,
-    backend: str = "bitplane",
-    seed: int = 0,
-) -> Dict:
-    dev = resolve_device(device, backend)
+def q6_queue(n_rows: int, units: int, seed: int = 0
+             ) -> Tuple[List[BbopInstr], Callable[[List], Dict]]:
+    """Generate a ``n_rows`` lineitem table from ``seed`` and build the
+    Q6 queue over it, one ``Ref`` chain per row shard (``units`` is how
+    many chains the target engine runs concurrently).
+
+    Returns ``(queue, finish)``: ``finish(results)`` takes the queue's
+    dispatched results from any engine, verifies them against the numpy
+    query oracle (raising :class:`~repro.apps.runtime.AppVerificationError`
+    on a mismatch) and returns the query's answer."""
     rng = np.random.default_rng(seed)
 
     shipdate = rng.integers(0, 2556, size=n_rows).astype(np.int64)      # days
@@ -49,7 +53,7 @@ def run(
 
     qb = QueueBuilder()
     shards = []
-    for sl in shard_slices(n_rows, n_parallel_units(dev)):
+    for sl in shard_slices(n_rows, units):
         sd, qt, dc, pr = shipdate[sl], quantity[sl], discount[sl], price[sl]
 
         def full(c, like):
@@ -68,19 +72,34 @@ def run(
                         np.zeros(sd.shape, np.int64), n_bits=28)
         shards.append((sl, (r_sel, r_rev)))
 
-    results = dev.dispatch(qb.queue)
-    sel = gather(results, [(sl, rs) for sl, (rs, _) in shards], n_rows)
-    masked = gather(results, [(sl, rr) for sl, (_, rr) in shards], n_rows)
-    revenue = int(masked.sum())
+    def finish(results) -> Dict:
+        sel = gather(results, [(sl, rs) for sl, (rs, _) in shards], n_rows)
+        masked = gather(results, [(sl, rr) for sl, (_, rr) in shards],
+                        n_rows)
+        revenue = int(masked.sum())
 
-    want_sel = ((shipdate >= t_lo) & (shipdate < t_hi)
-                & (discount >= d_lo) & (discount <= d_hi) & (quantity < q_lt))
-    want = int((price * discount)[want_sel].sum())
-    verify(revenue == want, "TPC-H Q6 revenue mismatch",
-           got=revenue, want=want)
-    verify(np.array_equal(sel.astype(bool), want_sel),
-           "TPC-H Q6 selection-vector mismatch")
+        want_sel = ((shipdate >= t_lo) & (shipdate < t_hi)
+                    & (discount >= d_lo) & (discount <= d_hi)
+                    & (quantity < q_lt))
+        want = int((price * discount)[want_sel].sum())
+        verify(revenue == want, "TPC-H Q6 revenue mismatch",
+               got=revenue, want=want)
+        verify(np.array_equal(sel.astype(bool), want_sel),
+               "TPC-H Q6 selection-vector mismatch")
+        return {"selected": int(sel.sum()), "revenue": revenue,
+                "output": masked}
 
-    return {"arch": "tpch_q6", "rows": n_rows, "selected": int(sel.sum()),
-            "revenue": revenue, "backend": dev.backend, "verified": True,
-            "output": masked, **dev.totals()}
+    return qb.queue, finish
+
+
+def run(
+    n_rows: int = 8192,
+    device: SimdramDevice | None = None,
+    backend: str = "bitplane",
+    seed: int = 0,
+) -> Dict:
+    dev = resolve_device(device, backend)
+    queue, finish = q6_queue(n_rows, n_parallel_units(dev), seed)
+    answer = finish(dev.dispatch(queue))
+    return {"arch": "tpch_q6", "rows": n_rows, "backend": dev.backend,
+            "verified": True, **answer, **dev.totals()}

@@ -287,8 +287,12 @@ class FaultRuntime:
     def stuck_masks(self, n_words: int) -> Tuple[np.ndarray, np.ndarray]:
         """(stuck0, stuck1) word masks for a state of ``n_words`` words —
         a prefix of the physical pattern, so widths never change which
-        columns are defective."""
-        return self._s0[:, :n_words], self._s1[:, :n_words]
+        columns are defective.  A state wider than the physical row
+        holds serialized re-invocations on the same columns, so the
+        pattern repeats with the row's period."""
+        reps = -(-n_words // self._s0.shape[1])
+        return (np.tile(self._s0, (1, reps))[:, :n_words],
+                np.tile(self._s1, (1, reps))[:, :n_words])
 
     def draw_keys(self) -> np.ndarray:
         """(n_units, 2) uint32 — fresh per-attempt PRNG keys, advanced
@@ -301,6 +305,61 @@ class FaultRuntime:
 # spare-lane replication (detection degree r = spare_lanes + 1)
 # ---------------------------------------------------------------------------
 
+@functools.lru_cache(maxsize=256)
+def replica_rotations(L: int, r: int) -> Tuple[int, ...]:
+    """Lane rotation of each of the ``r`` copies of an ``L``-lane
+    operand: copy *j* of lane *l* sits at column ``j*L + (l + rot[j]) % L``.
+
+    Copies that fit in one physical row need no rotation: the stride
+    ``L`` already puts them on distinct columns.  A wider state wraps
+    onto the same row (:meth:`FaultRuntime.stuck_masks` repeats with the
+    row's period), where an unrotated copy can land on the very column
+    of another — at ``L = 65,536`` every copy would, and the vote would
+    agree on the column's clamped value.  The rotation is then picked,
+    from a grid of candidates, to keep the copies of every lane as far
+    apart modulo the row as possible."""
+    P = _PHYS_COLUMNS
+    if r * L <= P:
+        return (0,) * r
+
+    def spread(s: int):
+        rot = [(j * s) % L for j in range(r)]
+        dist = P
+        for j in range(r):
+            for k in range(j):
+                base = (j - k) * L + rot[j] - rot[k]
+                # a rotated copy wraps: its later lanes sit L columns back
+                for wj in ((0, L) if rot[j] else (0,)):
+                    for wk in ((0, L) if rot[k] else (0,)):
+                        off = (base - wj + wk) % P
+                        dist = min(dist, off, P - off)
+        return dist, tuple(rot)
+
+    dist, rot = max((spread(s) for s in range(0, min(L, P),
+                                              max(1, P // 256))),
+                    key=lambda t: t[0])
+    if dist == 0:
+        raise ValueError(f"no rotation keeps the {r} copies of a {L}-lane "
+                         "operand on distinct physical columns")
+    return rot
+
+
+def _spread_replicas(vals: np.ndarray, r: int) -> np.ndarray:
+    """(..., L) -> (..., r*L) in the replica layout of
+    :func:`replica_rotations`."""
+    rot = replica_rotations(vals.shape[-1], r)
+    return np.concatenate([np.roll(vals, s, axis=-1) for s in rot], axis=-1)
+
+
+def _gather_replicas(flat: np.ndarray, r: int) -> np.ndarray:
+    """(r*L,) in the replica layout -> (L, r): column *j* is copy *j*,
+    lane-aligned with copy 0."""
+    L = flat.shape[-1] // r
+    rot = replica_rotations(L, r)
+    return np.stack([np.roll(row, -s)
+                     for row, s in zip(flat.reshape(r, L), rot)], axis=1)
+
+
 def _replicate_operand(o, r: int):
     from .bank import Ref, VerticalOperand
     if isinstance(o, Ref):
@@ -308,22 +367,23 @@ def _replicate_operand(o, r: int):
     if isinstance(o, VerticalOperand):
         n_bits = int(o.planes.shape[0])
         vals = unpack_bits(np.ascontiguousarray(o.planes), o.lanes)
-        rep = np.tile(vals, r)
+        rep = _spread_replicas(vals, r)
         cols = -(-max(len(rep), 1) // 32) * 32
         return VerticalOperand(pack_bits(rep, n_bits, cols), len(rep))
-    a = np.asarray(o)
-    return np.tile(a, (1,) * (a.ndim - 1) + (r,))
+    return _spread_replicas(np.asarray(o), r)
 
 
 def replicate_queue(queue, r: int) -> List:
     """Replicate every horizontal/vertical operand ``r``× with a
     *strided* layout: replica *j* of logical lane *l* sits at physical
-    column ``j*L + l`` (L = logical lane count).  Striding — rather
-    than placing replicas adjacently — keeps a spatial cluster of
-    stuck-at columns from covering every replica of one lane, which
-    would let the vote agree on a wrong clamped value.  ``Ref``
-    operands pass through — their producers are replicated too, so the
-    forwarded planes already carry the replicas."""
+    column ``j*L + l`` (L = logical lane count), rotated by
+    :func:`replica_rotations` when the copies wrap past one physical
+    row.  Striding — rather than placing replicas adjacently — keeps a
+    spatial cluster of stuck-at columns from covering every replica of
+    one lane, which would let the vote agree on a wrong clamped value.
+    ``Ref`` operands pass through — their producers are replicated too,
+    so the forwarded planes already carry the replicas.  Copy 0 is never
+    rotated, so the first ``L`` lanes of a result are the logical ones."""
     if r == 1:
         return list(queue)
     return [dataclasses.replace(
@@ -473,9 +533,9 @@ def faulty_execute(model: FaultModel, run: Callable, states: np.ndarray,
             vals_round = []
             disagree = np.zeros(L, bool)
             for rows in rows_of[j]:
-                cols = [unpack_bits(
+                cols = [_gather_replicas(unpack_bits(
                     np.ascontiguousarray(o[idx + (e.sid,)][rows]),
-                    e.lanes).reshape(r, L).T for o in outs]
+                    e.lanes), r) for o in outs]
                 grid = np.concatenate(cols, axis=1)
                 v, cnt = _majority(grid)
                 ok_round &= cnt * 2 > grid.shape[1]
@@ -525,7 +585,7 @@ def faulty_execute(model: FaultModel, run: Callable, states: np.ndarray,
     for j, (idx, e) in enumerate(ents):
         sub = final[idx + (e.sid,)]
         for o, rows in enumerate(rows_of[j]):
-            vals = np.tile(acc_vals[j][o], r)
+            vals = _spread_replicas(acc_vals[j][o], r)
             sub[list(rows)] = pack_bits(vals, e.spec.out_bits[o], n_cols)
     if sp is not None:
         tr.end(sp, runs=total_runs)

@@ -22,7 +22,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def constrain(x: jax.Array, *spec) -> jax.Array:
@@ -62,11 +61,11 @@ def pod_psum_compressed(mesh: Mesh, x: jax.Array) -> jax.Array:
     inner_spec = P("pod", *([None] * (x.ndim - 1))) if x.shape[0] % mesh.shape["pod"] == 0 \
         else P(*([None] * x.ndim))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda t: compressed_psum(t, "pod"),
         mesh=mesh,
         in_specs=(inner_spec,),
         out_specs=inner_spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x)

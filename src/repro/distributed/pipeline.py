@@ -21,7 +21,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -72,11 +71,11 @@ def gpipe(
             jnp.where(rank == n_stages - 1, out, jnp.zeros_like(out)), axis)
         return out
 
-    fn = shard_map(
+    fn = jax.shard_map(
         device_fn, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stacked_params, x)
 

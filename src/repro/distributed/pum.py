@@ -140,13 +140,11 @@ def _sharded_executor(mesh: Mesh) -> Callable:
     """One jitted shard_map executor per mesh — every chip on the same
     mesh shares it, so jit's shape cache (and the compiled executables)
     amortize across chips exactly like the vmap fallback's lru_cache."""
-    from jax.experimental.shard_map import shard_map
-
     bank_spec = P("data", None, None, None)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         chip_replay, mesh=mesh,
         in_specs=(bank_spec, bank_spec), out_specs=bank_spec,
-        check_rep=False))
+        check_vma=False))
 
 
 def make_faulty_chip_executor(
@@ -181,16 +179,14 @@ def make_faulty_chip_executor(
 
 @functools.lru_cache(maxsize=None)
 def _sharded_faulty_executor(mesh: Mesh) -> Callable:
-    from jax.experimental.shard_map import shard_map
-
     bank_spec = P("data", None, None, None)
     unit2 = P("data", None, None)      # keys (banks, subs, 2), masks (banks, subs, words)
     unit1 = P("data", None)            # dead flags / flip counts (banks, subs)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         faulty_chip_replay, mesh=mesh,
         in_specs=(bank_spec, bank_spec, unit2, unit2, unit2, unit1, P()),
         out_specs=(bank_spec, unit1),
-        check_rep=False))
+        check_vma=False))
 
 
 # ---------------------------------------------------------------------------
@@ -292,13 +288,11 @@ def make_channel_executor(
 def _sharded_channel_executor(mesh: Mesh) -> Callable:
     """One jitted 2-D shard_map executor per mesh — every channel on the
     same mesh shares it, exactly like the chip-level executor cache."""
-    from jax.experimental.shard_map import shard_map
-
     chip_spec = P("channel", "data", None, None, None)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         channel_replay, mesh=mesh,
         in_specs=(chip_spec, chip_spec), out_specs=chip_spec,
-        check_rep=False))
+        check_vma=False))
 
 
 def make_faulty_channel_executor(
@@ -339,16 +333,14 @@ def make_faulty_channel_executor(
 
 @functools.lru_cache(maxsize=None)
 def _sharded_faulty_channel_executor(mesh: Mesh) -> Callable:
-    from jax.experimental.shard_map import shard_map
-
     chip_spec = P("channel", "data", None, None, None)
     unit2 = P("channel", "data", None, None)   # keys / stuck masks
     unit1 = P("channel", "data", None)         # dead flags / flip counts
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         faulty_channel_replay, mesh=mesh,
         in_specs=(chip_spec, chip_spec, unit2, unit2, unit2, unit1, P()),
         out_specs=(chip_spec, unit1),
-        check_rep=False))
+        check_vma=False))
 
 
 # ---------------------------------------------------------------------------
@@ -436,10 +428,8 @@ def make_rank_executor(
 def _sharded_rank_executor(mesh: Mesh) -> Callable:
     """One jitted 3-D shard_map executor per mesh — every rank on the
     same mesh shares it, exactly like the channel-level executor cache."""
-    from jax.experimental.shard_map import shard_map
-
     channel_spec = P("rank", "channel", "data", None, None, None)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         rank_replay, mesh=mesh,
         in_specs=(channel_spec, channel_spec), out_specs=channel_spec,
-        check_rep=False))
+        check_vma=False))

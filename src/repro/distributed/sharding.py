@@ -36,19 +36,11 @@ Axis = Union[None, str, Tuple[str, ...]]
 
 
 def abstract_mesh(axis_sizes: Sequence[int], axis_names: Sequence[str]):
-    """Construct a ``jax.sharding.AbstractMesh`` across JAX API versions.
-
-    Newer JAX takes ``(axis_sizes, axis_names)``; the 0.4.x line takes a
-    single ``((name, size), ...)`` shape tuple.  All sharding rules here
-    only consume ``mesh.shape`` / ``mesh.axis_names``, which both forms
-    provide.
-    """
+    """A device-free ``jax.sharding.AbstractMesh``: the sharding rules
+    here only consume ``mesh.shape`` / ``mesh.axis_names``."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 def data_axes(mesh: Mesh) -> Tuple[str, ...]:

@@ -23,13 +23,15 @@ paper's "one TRA = one command" inner loop.
 from __future__ import annotations
 
 import functools
-from typing import List, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.logic import Circuit
+
+from . import resolve_interpret
 
 DEFAULT_BLOCK_W = 512
 
@@ -60,7 +62,7 @@ def circuit_on_planes(
     operand_planes: Sequence[jax.Array],
     *,
     block_w: int = DEFAULT_BLOCK_W,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Execute a MAJ/NOT circuit on vertical-layout operands via Pallas.
 
@@ -86,6 +88,6 @@ def circuit_on_planes(
         in_specs=in_specs,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((n_out, w_total), jnp.uint32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )
     return fn(*[p.astype(jnp.uint32) for p in operand_planes])

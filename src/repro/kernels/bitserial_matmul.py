@@ -34,11 +34,13 @@ same role SIMDRAM's offload decision plays against the CPU.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import resolve_interpret
 
 def _popcount(v: jax.Array) -> jax.Array:
     # masks constructed inside the traced body (pallas kernels cannot
@@ -79,7 +81,7 @@ def binary_matmul(  # noqa: D401
     bm: int = 128,
     bn: int = 128,
     bk: int = 64,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """out[m,n] = Σ_k popcount(a_words[m,k] & w_words[k,n]).
 
@@ -103,6 +105,6 @@ def binary_matmul(  # noqa: D401
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )
     return fn(a_words.astype(jnp.uint32), w_words.astype(jnp.uint32))

@@ -7,8 +7,9 @@
   quantized_matmul       — offload-style dispatch: bit-serial path for
                            ≤2-bit operands, jnp (MXU) int path otherwise
 
-All wrappers run the kernels in interpret mode by default (this container
-is CPU-only); pass interpret=False on real TPUs.  Oracles in ref.py.
+``interpret=None`` (the default) compiles the kernels on an accelerator
+and interprets them on the CPU; pass a bool to force either.  Oracles in
+ref.py.
 """
 
 from __future__ import annotations
@@ -42,7 +43,7 @@ def bbop_pallas(
     *operands: jax.Array,
     signed_out: bool = False,
     block_w: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Execute one SIMDRAM op via the fused bit-plane Pallas kernel."""
     spec, circ, ids = _compiled_op(name, n_bits)
@@ -64,7 +65,8 @@ def bbop_pallas(
     return outs[0] if len(outs) == 1 else tuple(outs)
 
 
-def h2v(values: jax.Array, n_bits: int = 32, *, interpret: bool = True) -> jax.Array:
+def h2v(values: jax.Array, n_bits: int = 32, *,
+        interpret: Optional[bool] = None) -> jax.Array:
     """Transposition unit, horizontal→vertical; returns (n_bits, N/32).
 
     Any lane count N is accepted (lanes pad to a multiple of 32, the
@@ -78,7 +80,8 @@ def h2v(values: jax.Array, n_bits: int = 32, *, interpret: bool = True) -> jax.A
     return planes[:n_bits]
 
 
-def v2h(planes: jax.Array, *, signed: bool = False, interpret: bool = True) -> jax.Array:
+def v2h(planes: jax.Array, *, signed: bool = False,
+        interpret: Optional[bool] = None) -> jax.Array:
     """Transposition unit, vertical→horizontal; accepts (k≤32, W) planes
     for any word count W (the kernel pads partial tiles internally)."""
     k, w = planes.shape
@@ -115,7 +118,7 @@ def bitserial_matmul(
     bm: int = 128,
     bn: int = 128,
     bk: int = 64,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Integer matmul  (M,K) × (K,N) -> (M,N) int32, computed bit-serially.
 

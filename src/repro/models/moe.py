@@ -199,7 +199,6 @@ def moe_forward_ep(
     Falls back to `moe_forward_grouped` when no mesh with a `model` axis
     is ambient (unit tests / single-host runs).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed.hints import _ambient_mesh
 
@@ -243,11 +242,11 @@ def moe_forward_ep(
         P("model", None, None),             # down
         P(bspec, None, None),               # x
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=in_specs,
         out_specs=(P(bspec, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     gate = p.get("gate")
     # weights may be quantized dicts; shard_map specs must match pytrees
@@ -264,8 +263,9 @@ def moe_forward_ep(
             spec_like(p["down"], P("model", None, None)),
             P(bspec, None, None),
         )
-        fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=(P(bspec, None, None), P()), check_rep=False)
+        fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=(P(bspec, None, None), P()),
+                           check_vma=False)
     return fn(p["router"], p["up"], gate, p["down"], x)
 
 

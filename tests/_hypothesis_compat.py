@@ -1,122 +1,4 @@
-"""Hypothesis compatibility shim for the test-suite.
+"""Re-export of Hypothesis's ``given``/``settings``/``st`` for the
+test-suite, which imports them from here."""
 
-When ``hypothesis`` is installed the real ``given``/``settings``/``st``
-are re-exported unchanged.  When it is absent (this container does not
-ship it) a deterministic fallback sampler stands in: each ``@given`` test
-runs ``max_examples`` times with values drawn from a ``numpy`` RNG seeded
-by the test's qualified name, so runs are reproducible and collection
-never fails on the import.
-
-The fallback implements exactly the strategy surface the suite uses:
-``st.integers``, ``st.sampled_from``, ``st.booleans`` and
-``st.composite``.  No shrinking, no example database — a failing example
-is reported with its draw index so it can be replayed.
-"""
-
-from __future__ import annotations
-
-try:
-    from hypothesis import given, settings, strategies as st  # noqa: F401
-
-    HAVE_HYPOTHESIS = True
-except ModuleNotFoundError:
-    import functools
-    import inspect
-    import zlib
-
-    import numpy as np
-
-    HAVE_HYPOTHESIS = False
-
-    class _Strategy:
-        def example(self, rng):
-            raise NotImplementedError
-
-    class _Integers(_Strategy):
-        def __init__(self, min_value, max_value):
-            self.lo, self.hi = int(min_value), int(max_value)
-
-        def example(self, rng):
-            return int(rng.integers(self.lo, self.hi, endpoint=True))
-
-    class _SampledFrom(_Strategy):
-        def __init__(self, elements):
-            self.elements = list(elements)
-
-        def example(self, rng):
-            return self.elements[int(rng.integers(len(self.elements)))]
-
-    class _Booleans(_Strategy):
-        def example(self, rng):
-            return bool(rng.integers(2))
-
-    class _Composite(_Strategy):
-        def __init__(self, fn, args, kwargs):
-            self.fn, self.args, self.kwargs = fn, args, kwargs
-
-        def example(self, rng):
-            return self.fn(lambda s: s.example(rng), *self.args, **self.kwargs)
-
-    class _StrategiesModule:
-        @staticmethod
-        def integers(min_value, max_value):
-            return _Integers(min_value, max_value)
-
-        @staticmethod
-        def sampled_from(elements):
-            return _SampledFrom(elements)
-
-        @staticmethod
-        def booleans():
-            return _Booleans()
-
-        @staticmethod
-        def composite(fn):
-            def factory(*args, **kwargs):
-                return _Composite(fn, args, kwargs)
-
-            return factory
-
-    st = _StrategiesModule()
-
-    def settings(max_examples: int = 20, deadline=None, **_ignored):
-        def deco(fn):
-            fn._compat_max_examples = max_examples
-            return fn
-
-        return deco
-
-    def given(*strategies):
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                n = getattr(wrapper, "_compat_max_examples",
-                            getattr(fn, "_compat_max_examples", 20))
-                rng = np.random.default_rng(
-                    zlib.crc32(fn.__qualname__.encode()))
-                # like hypothesis, strategies bind to the TRAILING
-                # parameters; leading ones are pytest fixtures
-                names = list(inspect.signature(fn).parameters)
-                names = names[len(names) - len(strategies):]
-                for i in range(n):
-                    drawn = [s.example(rng) for s in strategies]
-                    try:
-                        fn(*args, **dict(zip(names, drawn)), **kwargs)
-                    except Exception as e:  # annotate with the draw index
-                        raise AssertionError(
-                            f"falsifying example #{i} of {fn.__qualname__}: "
-                            f"{drawn!r}") from e
-
-            # strategies fill the test's trailing parameters; anything
-            # before them (pytest fixtures) stays in the visible signature
-            params = list(inspect.signature(fn).parameters.values())
-            kept = params[: len(params) - len(strategies)]
-            wrapper.__signature__ = inspect.Signature(kept)
-            del wrapper.__wrapped__
-            # keep the settings attribute visible if @settings is applied
-            # above @given
-            if hasattr(fn, "_compat_max_examples"):
-                wrapper._compat_max_examples = fn._compat_max_examples
-            return wrapper
-
-        return deco
+from hypothesis import given, settings, strategies as st  # noqa: F401

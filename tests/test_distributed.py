@@ -17,7 +17,6 @@ _SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.train.compression import compressed_psum
 
@@ -25,9 +24,9 @@ _SCRIPT = textwrap.dedent("""
     # per-pod distinct gradient shards; compressed psum over 'pod'
     g = jnp.stack([jnp.linspace(-1, 1, 512), jnp.linspace(0, 2, 512)])
 
-    fn = shard_map(lambda t: compressed_psum(t[0], "pod"),
-                   mesh=mesh, in_specs=(P("pod"),), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(lambda t: compressed_psum(t[0], "pod"),
+                       mesh=mesh, in_specs=(P("pod"),), out_specs=P(),
+                       check_vma=False)
     out = fn(g.reshape(2, 1, 512))
     want = np.asarray(g).sum(0)
     err = np.abs(np.asarray(out) - want).max()

@@ -206,6 +206,81 @@ def test_stuck_column_clusters_survive_strided_replicas():
     assert fs.detected > 0 and fs.corrected > 0
 
 
+def test_stuck_masks_repeat_past_the_physical_row():
+    """A state wider than one physical row (65,536 columns, e.g. a
+    spare-replicated 65,536-lane operand) holds re-invocations on the
+    same columns: its masks repeat the row's defect pattern."""
+    from repro.core.fault import FaultRuntime
+
+    rt = FaultRuntime(FaultModel(stuck_lane_rate=0.05, seed=3), (0,), 2)
+    row0, row1 = rt.stuck_masks(2048)
+    assert row0.any() and row1.any()
+    s0, s1 = rt.stuck_masks(5000)
+    assert s0.shape == s1.shape == (2, 5000)
+    for got, row in ((s0, row0), (s1, row1)):
+        np.testing.assert_array_equal(got[:, :2048], row)
+        np.testing.assert_array_equal(got[:, 2048:4096], row)
+        np.testing.assert_array_equal(got[:, 4096:], row[:, :904])
+
+
+@pytest.mark.parametrize("lanes", [40, 32_768, 65_535, 65_536, 98_304,
+                                   131_072])
+@pytest.mark.parametrize("r", [2, 3])
+def test_replicas_of_a_lane_never_share_a_physical_column(lanes, r):
+    """Every copy of a lane sits on its own physical column modulo the
+    65,536-column row, stuck_cluster (4) or more apart once the copies
+    wrap past the row, and the layout round-trips."""
+    from repro.core.fault import (_PHYS_COLUMNS, _gather_replicas,
+                                  _spread_replicas)
+
+    vals = np.arange(lanes, dtype=U)
+    rep = _spread_replicas(vals, r)
+    assert rep.shape == (r * lanes,)
+    assert np.array_equal(rep[:lanes], vals)
+    col = np.empty((r, lanes), np.int64)
+    for j in range(r):
+        col[j, rep[j * lanes:(j + 1) * lanes].astype(np.int64)] = (
+            j * lanes + np.arange(lanes))
+    phys = col % _PHYS_COLUMNS
+    margin = 4 if r * lanes > _PHYS_COLUMNS else 1
+    for j in range(r):
+        for k in range(j):
+            d = (phys[j] - phys[k]) % _PHYS_COLUMNS
+            assert np.minimum(d, _PHYS_COLUMNS - d).min() >= margin
+    grid = _gather_replicas(rep, r)
+    assert np.array_equal(grid, np.repeat(vals[:, None], r, axis=1))
+
+
+@pytest.mark.parametrize("spare_lanes,n_subarrays,rate,seed,outcome", [
+    (1, 4, 3e-5, 3, "remapped"),     # a clean subarray is left to move to
+    (1, 2, 0.002, 13, "exhausted"),  # every subarray defective
+    (2, 2, 0.002, 13, "outvoted"),
+])
+def test_stuck_columns_caught_at_row_width(spare_lanes, n_subarrays, rate,
+                                           seed, outcome):
+    """At 65,536 lanes every copy of a lane wraps onto the same physical
+    row: stuck columns must still split the vote, never return a wrong
+    answer.  One spare detects and moves the work to a clean subarray
+    (or, with none left, gives up); two spares out-vote the stuck copy."""
+    q = _small_queue(lanes=65_536)
+    ref = Bank(n_subarrays=n_subarrays).dispatch(_small_queue(lanes=65_536))
+    bank = Bank(n_subarrays=n_subarrays,
+                fault=FaultModel(p_flip=0.0, stuck_lane_rate=rate,
+                                 spare_lanes=spare_lanes, seed=seed,
+                                 max_redispatches=1))
+    if outcome == "exhausted":
+        with pytest.raises(FaultExhaustedError):
+            bank.dispatch(q)
+    else:
+        assert _exact(bank.dispatch(q), ref)
+    fs = bank.stats.faults
+    assert fs.detected > 0
+    if outcome == "remapped":
+        assert fs.redispatches > 0 and fs.host_fallbacks == 0
+    if outcome == "outvoted":
+        assert fs.corrected > 0
+
+
 def test_exhaustion_raises():
     bank = Bank(n_subarrays=2,
                 fault=FaultModel(p_flip=0.0, dead_unit_rate=1.0,

@@ -1,4 +1,7 @@
-"""Pallas kernels vs ref.py oracles: shape/dtype sweeps (interpret mode)."""
+"""Pallas kernels vs ref.py oracles: shape/dtype sweeps.
+
+On the CPU the kernels run in interpret mode; tests/test_tpu_compile.py
+compiles the same kernels for a TPU."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -8,7 +11,9 @@ from _hypothesis_compat import given, settings, st
 from repro.kernels import ops as kops
 from repro.kernels import ref
 from repro.kernels.bitserial_matmul import binary_matmul
-from repro.kernels.transpose_kernel import h2v_pallas, v2h_pallas
+from repro.core import bitplane
+from repro.kernels.transpose_kernel import (DEFAULT_BLOCK_B, h2v_pallas,
+                                            v2h_pallas)
 
 
 # -- transpose kernel ---------------------------------------------------------
@@ -30,6 +35,22 @@ def test_transpose_involution(seed):
     planes = h2v_pallas(v, block_b=4)
     back = v2h_pallas(planes, block_b=4)
     np.testing.assert_array_equal(np.asarray(back), np.asarray(v))
+
+
+@pytest.mark.parametrize("lanes", [
+    32 * (DEFAULT_BLOCK_B + 130),   # one full block + a partial tail tile
+    65_536,
+])
+def test_transpose_bit_exact_at_width(lanes):
+    rng = np.random.default_rng(lanes)
+    v = jnp.asarray(rng.integers(0, 2**32, size=lanes, dtype=np.uint32))
+    planes = h2v_pallas(v)
+    np.testing.assert_array_equal(np.asarray(planes),
+                                  np.asarray(ref.transpose32_ref(v)))
+    np.testing.assert_array_equal(np.asarray(planes),
+                                  np.asarray(bitplane.pack(v, 32)))
+    np.testing.assert_array_equal(np.asarray(v2h_pallas(planes)),
+                                  np.asarray(v))
 
 
 # -- binary popcount matmul ---------------------------------------------------

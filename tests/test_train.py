@@ -124,11 +124,10 @@ def test_compression_roundtrip_bounded_error(seed, scale):
 def test_compressed_psum_single_device():
     # axis of size 1: compressed psum == identity up to quantization error
     mesh = jax.make_mesh((1,), ("pod",))
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     x = jnp.linspace(-1, 1, 256)
-    fn = shard_map(lambda t: comp.compressed_psum(t, "pod"), mesh=mesh,
-                   in_specs=(P(),), out_specs=P(), check_rep=False)
+    fn = jax.shard_map(lambda t: comp.compressed_psum(t, "pod"), mesh=mesh,
+                       in_specs=(P(),), out_specs=P(), check_vma=False)
     y = fn(x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(x), atol=1e-2)
 
