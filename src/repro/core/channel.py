@@ -788,8 +788,8 @@ class SimdramChannel:
         for c, entries_by_bank in chips_entries:
             chip = self.chips[c]
             snap = [getattr(chip.stats, f) for f in _TRANSPOSE]
-            chip._harvest_round(queue, (entries_by_bank, out[c]),
-                                planes_cache, needed, results)
+            chip._unpack_round(queue, entries_by_bank, out[c],
+                               planes_cache, needed, results)
             for f, v0 in zip(_TRANSPOSE, snap):
                 setattr(self.stats, f,
                         getattr(self.stats, f)

@@ -367,24 +367,29 @@ class SimdramChip:
                        for _, wave in round_waves
                        for i in wave for o in queue[i].operands):
                     self._harvest_round(queue, pending, planes_cache,
-                                        needed, results)
+                                        needed, results, barrier=True)
                     pending = None
             entries_by_bank, fut = self._pack_round(
                 queue, round_waves, lanes, planes_cache)
-            self._account_round(queue, entries_by_bank)
+            if tr is not None:
+                with tr.span("chip.account", cat="account"):
+                    self._account_round(queue, entries_by_bank)
+            else:
+                self._account_round(queue, entries_by_bank)
             if pending is not None:
                 # double buffering: round k harvests only after round
                 # k+1 was packed and submitted
                 self._harvest_round(queue, pending, planes_cache, needed,
-                                    results)
+                                    results, barrier=False)
             pending = (entries_by_bank, fut)
         if pending is not None:
             if tr is not None:
-                with tr.span("chip.drain", cat="drain"):
+                with tr.span("chip.drain", cat="wait"):
                     jax.block_until_ready(pending[1])  # drain the pipeline
             else:
                 jax.block_until_ready(pending[1])     # drain the pipeline
-            self._harvest_round(queue, pending, planes_cache, needed, results)
+            self._harvest_round(queue, pending, planes_cache, needed,
+                                results, barrier=True)
         self.stats.wall_s += time.perf_counter() - t0
         if root is not None:
             tr.end(root)
@@ -461,12 +466,18 @@ class SimdramChip:
              tuple(bank_keys)),
             lambda: self._build_round_tables(bank_keys, n_cmds))
         if sp is not None:
-            tr.end(sp)
+            # μProgram commands of the packed slots before NOP padding,
+            # and the commands the stacked scan steps through
+            tr.end(sp,
+                   cmds_useful=sum(len(e.uprog.commands)
+                                   for _, entries in entries_by_bank
+                                   for e in entries),
+                   cmds_replayed=self.n_banks * self.n_subarrays * n_cmds)
         pack_s = time.perf_counter() - t_pack
         self.stats.pack_wall_s += pack_s
         for b, _ in round_waves:
             self.banks[b].stats.pack_wall_s += pack_s / len(round_waves)
-        sp = (tr.begin("chip.replay", cat="replay", banks=len(round_waves))
+        sp = (tr.begin("chip.submit", cat="submit", banks=len(round_waves))
               if tr is not None else None)
         fut = self._submit_round(states, tables, entries_by_bank)
         if sp is not None:
@@ -474,13 +485,19 @@ class SimdramChip:
         return entries_by_bank, fut
 
     def _submit_round(self, states, tables, entries_by_bank):
-        """Submit one stacked chip round.  Fault-free: the async
-        executor call, untouched.  Fault-injected: the synchronous
-        detect/retry/heal loop over the chip-tier faulty executor; the
-        healed numpy stack drains through ``_harvest_round`` exactly
-        like a device future."""
+        """Submit one stacked chip round.  Fault-free: the state copy to
+        the device and the async executor call (the command tables are
+        already device-resident, from ``TABLE_CACHE``).  Fault-injected:
+        the synchronous detect/retry/heal loop over the chip-tier faulty
+        executor; the healed numpy stack drains through
+        ``_harvest_round`` exactly like a device future."""
         if self.fault is None:
-            return self.executor.run(jnp.asarray(states), tables)
+            tr = active_tracer()
+            if tr is None:
+                return self.executor.run(jnp.asarray(states), tables)
+            with tr.span("chip.h2d", cat="fetch", bytes=states.nbytes):
+                on_device = jnp.asarray(states)
+            return self.executor.run(on_device, tables)
         from .fault import faulty_execute
         slabs = [((b,), entries, self.banks[b]._fault_rt)
                  for b, entries in entries_by_bank]
@@ -551,29 +568,50 @@ class SimdramChip:
             tr.charge("chip.replay", round_s)
         return bank_waves
 
-    def _harvest_round(self, queue, pending, planes_cache, needed, results):
-        """Materialize one completed chip round, bank slab by bank slab
+    def _harvest_round(self, queue, pending, planes_cache, needed, results,
+                       barrier: bool):
+        """Materialize one completed chip round: copy its state to the
+        host, then unpack it bank slab by bank slab.
+
+        ``barrier`` says whether the device had no later round queued
+        while this one was harvested: a stage barrier, or the last
+        round of the queue.  Traced, the ``chip.unpack`` span splits the
+        harvest into the wait for the device (``chip.harvest.wait``),
+        the device-to-host copy (``chip.harvest.fetch``, with its
+        ``bytes``) and one ``bank.harvest_out`` per bank slab."""
+        entries_by_bank, fut = pending
+        tr = active_tracer()
+        if tr is None:
+            self._unpack_round(queue, entries_by_bank, np.asarray(fut),
+                               planes_cache, needed, results)
+            return
+        with tr.span("chip.unpack", cat="unpack", barrier=barrier):
+            with tr.span("chip.harvest.wait", cat="wait"):
+                jax.block_until_ready(fut)
+            with tr.span("chip.harvest.fetch", cat="fetch") as sp:
+                out = np.asarray(fut)
+                sp.attrs["bytes"] = out.nbytes
+            self._unpack_round(queue, entries_by_bank, out, planes_cache,
+                               needed, results)
+
+    def _unpack_round(self, queue, entries_by_bank, out, planes_cache,
+                      needed, results):
+        """Unpack one round's host copy ``out``, bank slab by bank slab
         (forwarded planes published per bank — chains are bank-local)."""
         tr = active_tracer()
-        if tr is not None:
-            with tr.span("chip.unpack", cat="unpack"):
-                self._harvest_round_impl(queue, pending, planes_cache,
-                                         needed, results)
-            return
-        self._harvest_round_impl(queue, pending, planes_cache, needed,
-                                 results)
-
-    def _harvest_round_impl(self, queue, pending, planes_cache, needed,
-                            results):
-        entries_by_bank, fut = pending
-        out = np.asarray(fut)
         for b, entries in entries_by_bank:
             bank = self.banks[b]
             skips0 = bank.stats.transpositions_skipped
             saved0 = bank.stats.transpose_s_saved
             paid0 = bank.stats.transpose_s
-            bank._harvest_out(queue, entries, out[b], planes_cache, needed,
-                              results)
+            if tr is None:
+                bank._harvest_out(queue, entries, out[b], planes_cache,
+                                  needed, results)
+            else:
+                with tr.span("bank.harvest_out", cat="unpack",
+                             lane=bank._lane, slots=len(entries)):
+                    bank._harvest_out(queue, entries, out[b], planes_cache,
+                                      needed, results)
             self.stats.transpositions_skipped += (
                 bank.stats.transpositions_skipped - skips0)
             self.stats.transpose_s_saved += (

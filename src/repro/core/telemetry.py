@@ -3,6 +3,8 @@
 Every stage of a dispatch — queue validation, wave/round/super-round
 packing, ``TABLE_CACHE`` lookup, replay, transpose, fault handling,
 host<->chip transfer, unpack, serve-tier fallback — can open a *span*.
+While a span is open it is also a ``jax.profiler.TraceAnnotation``, so
+a profile shows it beside the device's operations.
 A span carries two clocks:
 
 * **measured** — host wall seconds (``time.perf_counter`` deltas), i.e.
@@ -89,6 +91,9 @@ class Span:
     charges: List[Tuple[str, float]] = field(default_factory=list)
     children: List["Span"] = field(default_factory=list)
     seq: int = 0
+    # the profiler annotation entered at begin() and exited at end(); a
+    # handle only, never recorded or compared
+    annotation: Any = field(default=None, repr=False, compare=False)
 
     @property
     def modeled_s(self) -> float:
@@ -143,6 +148,12 @@ class Tracer:
 
     Single-threaded by design (the ladder is a synchronous caller); the
     open-span stack is plain process state, never captured by jit.
+
+    Every span opened with :meth:`begin` (or :meth:`span`) also enters a
+    ``jax.profiler.TraceAnnotation`` of the same name, exited when the
+    span ends, so a profile taken around a traced dispatch shows the
+    spans on the host plane, on the device trace's clock.  Leaf spans
+    from :meth:`event` are timed outside the tracer and emit none.
     """
 
     def __init__(self, max_dispatches: int = 64, max_incidents: int = 16):
@@ -161,13 +172,21 @@ class Tracer:
 
     def begin(self, name: str, cat: str = "stage", lane: str = "", **attrs: Any) -> Span:
         self._seq += 1
-        sp = Span(name=name, cat=cat, lane=lane, t0=time.perf_counter(),
-                  attrs=dict(attrs), seq=self._seq)
+        sp = Span(name=name, cat=cat, lane=lane, attrs=dict(attrs),
+                  seq=self._seq)
         if self._stack:
             if not sp.lane:
                 sp.lane = self._stack[-1].lane
             self._stack[-1].children.append(sp)
         self._stack.append(sp)
+        # imported here so that this module imports without JAX; outside
+        # a profile, entering the annotation is a cheap no-op.  Every
+        # allocation comes before the two clock readings, so that a
+        # garbage collection it sets off cannot fall between them.
+        from jax.profiler import TraceAnnotation
+        sp.annotation = TraceAnnotation(name)
+        sp.annotation.__enter__()
+        sp.t0 = time.perf_counter()
         return sp
 
     def end(self, span: Span, **attrs: Any) -> Span:
@@ -178,6 +197,8 @@ class Tracer:
         # always end in LIFO order)
         while self._stack:
             top = self._stack.pop()
+            top.annotation.__exit__(None, None, None)
+            top.annotation = None
             if top is span:
                 break
         if not self._stack:
@@ -236,12 +257,6 @@ class Tracer:
         if target is not None:
             target.charges.append((cat, seconds))
 
-    def count(self, cat: str, n: int = 1) -> None:
-        """Record a modeled count (e.g. a skipped transposition) as attrs."""
-        if self._stack:
-            attrs = self._stack[-1].attrs
-            attrs[cat] = attrs.get(cat, 0) + n
-
     def modeled_total(self, cat: str) -> float:
         """Left-fold sum of every charge in ``cat`` (bit-exact vs Stats)."""
         total = 0.0
@@ -251,14 +266,6 @@ class Tracer:
 
     def modeled_categories(self) -> Tuple[str, ...]:
         return tuple(sorted(self._charges))
-
-    def wall_total(self, name: Optional[str] = None) -> float:
-        total = 0.0
-        for root in self.roots:
-            for sp in root.walk():
-                if name is None or sp.name == name:
-                    total += sp.wall_s
-        return total
 
     # -- flight recorder ---------------------------------------------------
 

@@ -10,6 +10,7 @@ Chrome-trace / JSONL / stage-summary exporters (validated with the same
 schema gate CI runs via ``scripts/check_trace.py``).
 """
 
+import dataclasses
 import importlib.util
 import json
 import pathlib
@@ -439,3 +440,143 @@ def test_jsonl_and_stage_summary(tmp_path):
     assert rows["bank.dispatch"]["modeled_us"] == pytest.approx(
         sum(trace["otherData"]["modeled_totals_s"].values()) * 1e6,
         rel=1e-9)
+
+
+# ---------------------------------------------------------------------------
+# the chip tier's harvest, submit and command counters
+# ---------------------------------------------------------------------------
+
+def _chip_device():
+    from dataclasses import replace
+
+    from repro.core.isa import SimdramDevice
+    from repro.core.timing import DDR4
+    cfg = replace(DDR4, n_banks=2, subarrays_per_bank=2,
+                  columns_per_subarray=256)
+    return SimdramDevice(cfg=cfg, backend="chip")
+
+
+def _recording(chip):
+    """Wrap the chip's executor: record each submitted state, table
+    stack and replay output."""
+    from dataclasses import replace
+    calls = []
+    plain = chip.executor
+
+    def run(states, tables):
+        out = plain.run(states, tables)
+        calls.append((states, tables, out))
+        return out
+
+    chip.executor = replace(plain, run=run)
+    return calls
+
+
+def test_annotations_close_with_their_spans_and_stay_out_of_records():
+    tr = Tracer()
+    root = tr.begin("root")
+    tr.begin("left_open")
+    child = tr.begin("child")
+    assert child.annotation is not None
+    tr.end(root)                      # LIFO pop through the open spans
+    assert all(s.annotation is None for s in root.walk())
+    tr.begin("a")
+    tr.begin("b")
+    tr.unwind(0)
+    assert all(s.annotation is None for s in tr.roots[-1].walk())
+    assert "annotation" not in root.to_record()
+    assert "annotation" not in repr(root)
+    ev = tr.event("leaf", wall_s=1.0)
+    assert ev.annotation is None
+    twin = tr.begin("x")
+    assert twin == dataclasses.replace(twin, annotation=None)
+    tr.end(twin)
+
+
+def test_chip_harvest_splits_into_wait_fetch_and_unpack():
+    from repro.core.bank import cached_table
+
+    dev = _chip_device()
+    queue = _queue(lanes=128)
+    with obs.enabled() as tr:
+        calls = _recording(dev.chip())
+        dev.dispatch(queue)
+        root = tr.roots[-1]
+    assert root.name == "device.dispatch"
+    unpacks = root.find("chip.unpack")
+    assert len(unpacks) == len(calls) == 2
+    for sp in unpacks:
+        assert sp.cat == "unpack"
+        kids = [(c.name, c.cat) for c in sp.children]
+        assert kids[:2] == [("chip.harvest.wait", "wait"),
+                            ("chip.harvest.fetch", "fetch")]
+        assert kids[2:] and all(k == ("bank.harvest_out", "unpack")
+                                for k in kids[2:])
+    # the multiplication forwards the addition's planes: the first round
+    # is harvested at the stage barrier, before the second is packed
+    assert unpacks[0].attrs["barrier"] is True
+    assert [sp.cat for sp in root.find("chip.drain")] == ["wait"]
+    fetches = root.find("chip.harvest.fetch")
+    assert [sp.attrs["bytes"] for sp in fetches] == [
+        np.asarray(out).nbytes for _, _, out in calls]
+    submits = root.find("chip.submit")
+    assert [sp.cat for sp in submits] == ["submit", "submit"]
+    h2d = [c for sp in submits for c in sp.children]
+    assert [(c.name, c.cat) for c in h2d] == [("chip.h2d", "fetch")] * 2
+    assert [c.attrs["bytes"] for c in h2d] == [
+        states.nbytes for states, _, _ in calls]
+    assert [sp.cat for sp in root.find("chip.account")] == ["account"] * 2
+    packs = root.find("chip.pack_round")
+    assert sum(sp.attrs["cmds_useful"] for sp in packs) == sum(
+        len(cached_table(i.op, i.n_bits, "mig")[1].commands) for i in queue)
+    assert [sp.attrs["cmds_replayed"] for sp in packs] == [
+        int(np.prod(tables.shape[:3])) for _, tables, _ in calls]
+    # the last round drains with nothing queued behind it: a barrier too
+    assert unpacks[-1].attrs["barrier"] is True
+
+
+def test_double_buffered_round_is_harvested_without_a_barrier():
+    rng = np.random.default_rng(1)
+    a, b = (rng.integers(0, 256, 128).astype(U) for _ in range(2))
+    # more independent bbops than the 2 x 2 slots: two rounds, the
+    # first harvested only after the second was submitted
+    queue = [BbopInstr("addition", (a, b), 8) for _ in range(6)]
+    dev = _chip_device()
+    with obs.enabled() as tr:
+        dev.dispatch(queue)
+        root = tr.roots[-1]
+    assert [sp.attrs["barrier"] for sp in root.find("chip.unpack")] == [
+        False, True]
+
+
+def test_chip_spans_reach_the_profiler_host_plane(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    dev = _chip_device()
+    dev.dispatch(_queue(lanes=128))           # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.enabled():
+            dev.dispatch(_queue(lanes=128))
+    files = sorted(tmp_path.rglob("*.xplane.pb"))
+    assert files
+    data = ProfileData.from_file(str(files[-1]))
+    names = {ev.name for plane in data.planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert {"chip.pack_round", "chip.harvest.fetch", "chip.unpack",
+            "device.dispatch"} <= names
+
+
+def test_traced_chip_dispatch_is_bit_identical_and_compiles_nothing():
+    from repro.core.control_unit import trace_counts
+
+    plain = _chip_device()
+    r_plain = plain.dispatch(_queue(lanes=128, seed=7))
+    counts = dict(trace_counts())
+    with obs.enabled():
+        traced = _chip_device()
+        r_traced = traced.dispatch(_queue(lanes=128, seed=7))
+    assert dict(trace_counts()) == counts
+    assert _exact(r_traced, r_plain)
+    assert traced.chip().stats.latency_s == plain.chip().stats.latency_s
