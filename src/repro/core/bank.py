@@ -260,7 +260,7 @@ class VerticalOperand:
             return vals
         from .subarray import unpack_bits
         vals = unpack_bits(
-            np.ascontiguousarray(self.planes), self.lanes).astype(np.int64)
+            np.ascontiguousarray(self.planes), self.lanes).view(np.int64)
         if signed and n_bits < 64:
             vals = np.where(vals >= (1 << (n_bits - 1)),
                             vals - (1 << n_bits), vals)

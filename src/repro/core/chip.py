@@ -578,7 +578,9 @@ class SimdramChip:
         round of the queue.  Traced, the ``chip.unpack`` span splits the
         harvest into the wait for the device (``chip.harvest.wait``),
         the device-to-host copy (``chip.harvest.fetch``, with its
-        ``bytes``) and one ``bank.harvest_out`` per bank slab."""
+        ``bytes``) and one ``bank.harvest_out`` per bank slab (``planes``:
+        output planes it converts to horizontal, ``keep_vertical`` ones
+        left out)."""
         entries_by_bank, fut = pending
         tr = active_tracer()
         if tr is None:
@@ -608,8 +610,11 @@ class SimdramChip:
                 bank._harvest_out(queue, entries, out[b], planes_cache,
                                   needed, results)
             else:
+                planes = sum(sum(e.spec.out_bits) for e in entries
+                             if not queue[e.qi].keep_vertical)
                 with tr.span("bank.harvest_out", cat="unpack",
-                             lane=bank._lane, slots=len(entries)):
+                             lane=bank._lane, slots=len(entries),
+                             planes=planes):
                     bank._harvest_out(queue, entries, out[b], planes_cache,
                                       needed, results)
             self.stats.transpositions_skipped += (

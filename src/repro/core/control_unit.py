@@ -97,7 +97,7 @@ def read_outputs(
 
     outs = []
     for w, rows in zip(out_bits, output_plane_rows(out_bits, uprog)):
-        vals = unpack_bits(state[rows], lanes).astype(np.int64)
+        vals = unpack_bits(state[rows], lanes).view(np.int64)
         if signed:
             vals = vals & ((1 << w) - 1)
             vals = np.where(vals >= (1 << (w - 1)), vals - (1 << w), vals)

@@ -110,19 +110,34 @@ def pack_bits(values: np.ndarray, n_bits: int, n_columns: int) -> np.ndarray:
     ).view(np.uint32).reshape(n_bits, -1)
 
 
+# _SPREAD[i, b]: byte b's bit k moved to bit i of byte k.  One plane
+# byte holds one bit of 8 lanes; spreading it puts that bit into each
+# lane's byte, at the plane's place within its group of 8 planes.
+_SPREAD = (np.unpackbits(np.arange(256, dtype=np.uint8)[:, None], axis=1,
+                         bitorder="little").view(np.uint64).reshape(1, 256)
+           << np.arange(8, dtype=np.uint64)[:, None])
+_SPREAD_ROW = np.arange(0, _SPREAD.size, 256, dtype=np.intp)[:, None]
+
+
 def unpack_bits(planes: np.ndarray, lanes: int) -> np.ndarray:
     """Vertical -> horizontal: (n_bits, n_words) uint32 -> (lanes,) uint64.
 
-    Vectorized: one unpackbits call over all planes, then a shift-OR
-    reduction."""
-    n_bits = planes.shape[0]
-    if n_bits == 0 or lanes == 0:
-        return np.zeros(lanes, dtype=np.uint64)
-    bits = np.unpackbits(
-        np.ascontiguousarray(planes).view(np.uint8), axis=1,
-        bitorder="little")[:, :lanes].astype(np.uint64)
-    shifts = np.arange(n_bits, dtype=np.uint64)[:, None]
-    return np.bitwise_or.reduce(bits << shifts, axis=0)
+    A bit-matrix transpose on the packed bytes: byte *g* of plane *p*
+    holds bit *p* of lanes 8g…8g+7.  Each group of 8 planes is looked up
+    in ``_SPREAD`` and OR-ed together — the 8×8 transpose — which gives,
+    viewed as bytes, output byte ``p // 8`` of each of the 8 lanes; it
+    lands in that byte column of a (lanes, 8) uint8 buffer, viewed as
+    uint64 at the end.  Temporaries hold about one byte per lane per
+    plane; plane bits past ``lanes`` are ignored."""
+    groups = -(-lanes // 8)          # plane bytes that hold a lane
+    by = np.ascontiguousarray(planes).view(np.uint8)[:, :groups]
+    out = np.zeros((groups * 8, 8), dtype=np.uint8)
+    for q in range(0, planes.shape[0], 8):
+        idx = by[q:q + 8].astype(np.intp)
+        idx += _SPREAD_ROW[:len(idx)]
+        out[:, q // 8] = np.bitwise_or.reduce(
+            np.take(_SPREAD, idx), axis=0).view(np.uint8)
+    return out.view(np.uint64).reshape(-1)[:lanes]
 
 
 def run_uprogram(

@@ -531,6 +531,10 @@ def test_chip_harvest_splits_into_wait_fetch_and_unpack():
         len(cached_table(i.op, i.n_bits, "mig")[1].commands) for i in queue)
     assert [sp.attrs["cmds_replayed"] for sp in packs] == [
         int(np.prod(tables.shape[:3])) for _, tables, _ in calls]
+    # every output plane of the queue is converted once, none is vertical
+    assert sum(sp.attrs["planes"] for sp in root.find("bank.harvest_out")) \
+        == sum(sum(cached_table(i.op, i.n_bits, "mig")[0].out_bits)
+               for i in queue)
     # the last round drains with nothing queued behind it: a barrier too
     assert unpacks[-1].attrs["barrier"] is True
 
@@ -547,6 +551,23 @@ def test_double_buffered_round_is_harvested_without_a_barrier():
         root = tr.roots[-1]
     assert [sp.attrs["barrier"] for sp in root.find("chip.unpack")] == [
         False, True]
+
+
+def test_harvest_out_counts_only_the_planes_it_converts():
+    from repro.core.bank import cached_table
+
+    rng = np.random.default_rng(2)
+    a, b = (rng.integers(0, 256, 128).astype(U) for _ in range(2))
+    queue = [BbopInstr("addition", (a, b), 8, keep_vertical=True),
+             BbopInstr("greater", (a, b), 8)]
+    dev = _chip_device()
+    with obs.enabled() as tr:
+        dev.dispatch(queue)
+        root = tr.roots[-1]
+    spans = root.find("bank.harvest_out")
+    assert spans
+    assert sum(sp.attrs["planes"] for sp in spans) == sum(
+        cached_table("greater", 8, "mig")[0].out_bits)
 
 
 def test_chip_spans_reach_the_profiler_host_plane(tmp_path):
