@@ -630,7 +630,8 @@ class SimdramChannel:
         tables = TABLE_CACHE.get(
             ("channel", self.n_chips, self.n_banks, self.n_subarrays,
              n_cmds, tuple(chip_keys)),
-            lambda: self._build_super_round_tables(chip_keys, n_cmds))
+            lambda: self._build_super_round_tables(chip_keys, n_cmds),
+            self.executor.sharding)
         if sp is not None:
             tr.end(sp)
         pack_s = time.perf_counter() - t_pack
@@ -692,7 +693,7 @@ class SimdramChannel:
         the healed numpy stack drains through ``_harvest_super_round``
         exactly like a device future."""
         if self.fault is None:
-            return self.executor.run(jnp.asarray(states), tables)
+            return self.executor.run(self.executor.place(states), tables)
         from .fault import faulty_execute
         slabs = [((c, b), entries, self.chips[c].banks[b]._fault_rt)
                  for c, entries_by_bank in chips_entries

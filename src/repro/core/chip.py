@@ -202,6 +202,12 @@ def sequential_dispatch(queue: Sequence[BbopInstr], n_banks: int = 4,
     return results, banks
 
 
+def _devices(arr) -> int:
+    """Devices a round's state lies on: 0 for a host array (the fault
+    path heals its rounds on the host)."""
+    return len(arr.sharding.device_set) if isinstance(arr, jax.Array) else 0
+
+
 class SimdramChip:
     """``n_banks`` banks × ``n_subarrays`` subarrays, one stacked replay.
 
@@ -464,7 +470,8 @@ class SimdramChip:
         tables = TABLE_CACHE.get(
             ("chip", self.n_banks, self.n_subarrays, n_cmds,
              tuple(bank_keys)),
-            lambda: self._build_round_tables(bank_keys, n_cmds))
+            lambda: self._build_round_tables(bank_keys, n_cmds),
+            self.executor.sharding)
         if sp is not None:
             # μProgram commands of the packed slots before NOP padding,
             # and the commands the stacked scan steps through
@@ -494,9 +501,12 @@ class SimdramChip:
         if self.fault is None:
             tr = active_tracer()
             if tr is None:
-                return self.executor.run(jnp.asarray(states), tables)
-            with tr.span("chip.h2d", cat="fetch", bytes=states.nbytes):
-                on_device = jnp.asarray(states)
+                return self.executor.run(self.executor.place(states), tables)
+            with tr.span("chip.h2d", cat="fetch", bytes=states.nbytes) as sp:
+                on_device = self.executor.place(states)
+                devices = _devices(on_device)
+                sp.attrs.update(devices=devices,
+                                shard_bytes=states.nbytes // devices)
             return self.executor.run(on_device, tables)
         from .fault import faulty_execute
         slabs = [((b,), entries, self.banks[b]._fault_rt)
@@ -590,7 +600,8 @@ class SimdramChip:
         with tr.span("chip.unpack", cat="unpack", barrier=barrier):
             with tr.span("chip.harvest.wait", cat="wait"):
                 jax.block_until_ready(fut)
-            with tr.span("chip.harvest.fetch", cat="fetch") as sp:
+            with tr.span("chip.harvest.fetch", cat="fetch",
+                         devices=_devices(fut)) as sp:
                 out = np.asarray(fut)
                 sp.attrs["bytes"] = out.nbytes
             self._unpack_round(queue, entries_by_bank, out, planes_cache,

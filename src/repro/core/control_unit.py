@@ -241,21 +241,26 @@ class TableCache:
         self.misses = 0
         self.evictions = 0
 
-    def get(self, key, build):
+    def get(self, key, build, sharding=None):
         """Return the cached device array for ``key``, building (and
-        device-committing) it on first use via ``build()``."""
+        device-committing) it on first use via ``build()``.  A miss is
+        placed on ``sharding``, the executor's input sharding, so each
+        device holds the slabs it replays (``None``: the default
+        device); an entry is cached per sharding."""
         from .telemetry import active_tracer
         tr = active_tracer()
+        key = (key, sharding)
         t = self._store.get(key)
         if t is None:
             self.misses += 1
             t0 = time.perf_counter() if tr is not None else 0.0
             arr = build()
-            t = self._store[key] = jax.device_put(arr)
+            t = self._store[key] = jax.device_put(arr, sharding)
             if tr is not None:
-                tr.event("table_cache.miss", cat="cache", tier=key[0],
+                tr.event("table_cache.miss", cat="cache", tier=key[0][0],
                          wall_s=time.perf_counter() - t0,
-                         bytes=int(arr.nbytes))
+                         bytes=int(arr.nbytes),
+                         devices=len(t.sharding.device_set))
             self.bytes += int(arr.nbytes)
             while self.bytes > self.max_bytes and len(self._store) > 1:
                 _, old = self._store.popitem(last=False)
@@ -265,7 +270,7 @@ class TableCache:
             self.hits += 1
             self._store.move_to_end(key)
             if tr is not None:
-                tr.event("table_cache.hit", cat="cache", tier=key[0])
+                tr.event("table_cache.hit", cat="cache", tier=key[0][0])
         return t
 
     def stats(self) -> Dict[str, int]:

@@ -378,7 +378,8 @@ class SimdramRank:
         tables = TABLE_CACHE.get(
             ("rank", self.n_channels, self.n_chips, self.n_banks,
              self.n_subarrays, n_cmds, tuple(channel_keys)),
-            lambda: self._build_rank_round_tables(channel_keys, n_cmds))
+            lambda: self._build_rank_round_tables(channel_keys, n_cmds),
+            self.executor.sharding)
         if sp is not None:
             tr.end(sp)
         pack_s = time.perf_counter() - t_pack
@@ -389,7 +390,7 @@ class SimdramRank:
         sp = (tr.begin("rank.replay", cat="replay",
                        channels=len(round_by_channel))
               if tr is not None else None)
-        fut = self.executor.run(jnp.asarray(states), tables)
+        fut = self.executor.run(self.executor.place(states), tables)
         if sp is not None:
             tr.end(sp)
         return channels_entries, fut

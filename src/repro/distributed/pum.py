@@ -40,8 +40,9 @@ from dataclasses import dataclass
 from typing import Callable, Optional, Sequence
 
 import jax
+import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.control_unit import (channel_batched_interpreter,
                                      channel_replay,
@@ -53,6 +54,12 @@ from repro.core.control_unit import (channel_batched_interpreter,
                                      rank_batched_interpreter, rank_replay)
 
 from .sharding import fit_spec
+
+#: how each tier's stacked ``(units..., n_subarrays, rows, words)`` states
+#: and ``(units..., n_subarrays, n_cmds, 13)`` tables split over its mesh
+BANK_SPEC = P("data", None, None, None)
+CHIP_SPEC = P("channel", "data", None, None, None)
+CHANNEL_SPEC = P("rank", "channel", "data", None, None, None)
 
 
 def _note_executor(kind: str, mesh: Optional[Mesh], sharded: bool) -> None:
@@ -84,13 +91,36 @@ class ChipExecutor:
     """A compiled chip-replay callable plus how it partitions.
 
     ``run(states, tables)`` returns the executed states asynchronously
-    (a jitted call either way); ``sharded`` tells whether bank slabs
-    execute on different devices (shard_map) or one device vmaps them.
+    (a jitted call either way).  ``spec`` is how a shard_map ``run``
+    splits its inputs over ``mesh``, bank slabs on different devices;
+    ``None`` when one device vmaps them.  :meth:`place` is the one way a
+    stacked host array reaches the device.
     """
 
     run: Callable
     mesh: Optional[Mesh]
-    sharded: bool
+    spec: Optional[P] = None
+
+    @property
+    def sharded(self) -> bool:
+        """Whether bank slabs execute on different devices."""
+        return self.spec is not None
+
+    @property
+    def sharding(self) -> Optional[NamedSharding]:
+        """Where ``run`` reads its inputs, or ``None`` for the default
+        device."""
+        if self.spec is None:
+            return None
+        return NamedSharding(self.mesh, self.spec)
+
+    def place(self, host: np.ndarray) -> jax.Array:
+        """Copy a stacked host array to where ``run`` reads it: each
+        unit slab straight onto the device that replays it, so nothing
+        is resharded on the device; one device takes it whole."""
+        if self.spec is None:
+            return jnp.asarray(host)
+        return jax.device_put(host, self.sharding)
 
     def describe(self) -> dict:
         """Flat summary for telemetry / benchmark artifacts."""
@@ -118,7 +148,7 @@ def make_chip_executor(
     """
     if use_shard_map is False:
         _note_executor("chip", None, False)
-        return ChipExecutor(chip_batched_interpreter(), None, False)
+        return ChipExecutor(chip_batched_interpreter(), None)
     if mesh is None:
         mesh = pum_mesh(n_banks)
     has_data = mesh is not None and "data" in tuple(mesh.axis_names)
@@ -130,9 +160,9 @@ def make_chip_executor(
                 f"shard_map requested but no multi-device mesh fits "
                 f"n_banks={n_banks} (devices={jax.device_count()})")
         _note_executor("chip", mesh, False)
-        return ChipExecutor(chip_batched_interpreter(), mesh, False)
+        return ChipExecutor(chip_batched_interpreter(), mesh)
     _note_executor("chip", mesh, True)
-    return ChipExecutor(_sharded_executor(mesh), mesh, True)
+    return ChipExecutor(_sharded_executor(mesh), mesh, BANK_SPEC)
 
 
 @functools.lru_cache(maxsize=None)
@@ -140,10 +170,9 @@ def _sharded_executor(mesh: Mesh) -> Callable:
     """One jitted shard_map executor per mesh — every chip on the same
     mesh shares it, so jit's shape cache (and the compiled executables)
     amortize across chips exactly like the vmap fallback's lru_cache."""
-    bank_spec = P("data", None, None, None)
     return jax.jit(jax.shard_map(
         chip_replay, mesh=mesh,
-        in_specs=(bank_spec, bank_spec), out_specs=bank_spec,
+        in_specs=(BANK_SPEC, BANK_SPEC), out_specs=BANK_SPEC,
         check_vma=False))
 
 
@@ -160,7 +189,7 @@ def make_faulty_chip_executor(
     identical."""
     if use_shard_map is False:
         _note_executor("chip.faulty", None, False)
-        return ChipExecutor(faulty_chip_batched_interpreter(), None, False)
+        return ChipExecutor(faulty_chip_batched_interpreter(), None)
     if mesh is None:
         mesh = pum_mesh(n_banks)
     has_data = mesh is not None and "data" in tuple(mesh.axis_names)
@@ -172,20 +201,19 @@ def make_faulty_chip_executor(
                 f"shard_map requested but no multi-device mesh fits "
                 f"n_banks={n_banks} (devices={jax.device_count()})")
         _note_executor("chip.faulty", mesh, False)
-        return ChipExecutor(faulty_chip_batched_interpreter(), mesh, False)
+        return ChipExecutor(faulty_chip_batched_interpreter(), mesh)
     _note_executor("chip.faulty", mesh, True)
-    return ChipExecutor(_sharded_faulty_executor(mesh), mesh, True)
+    return ChipExecutor(_sharded_faulty_executor(mesh), mesh, BANK_SPEC)
 
 
 @functools.lru_cache(maxsize=None)
 def _sharded_faulty_executor(mesh: Mesh) -> Callable:
-    bank_spec = P("data", None, None, None)
     unit2 = P("data", None, None)      # keys (banks, subs, 2), masks (banks, subs, words)
     unit1 = P("data", None)            # dead flags / flip counts (banks, subs)
     return jax.jit(jax.shard_map(
         faulty_chip_replay, mesh=mesh,
-        in_specs=(bank_spec, bank_spec, unit2, unit2, unit2, unit1, P()),
-        out_specs=(bank_spec, unit1),
+        in_specs=(BANK_SPEC, BANK_SPEC, unit2, unit2, unit2, unit1, P()),
+        out_specs=(BANK_SPEC, unit1),
         check_vma=False))
 
 
@@ -221,7 +249,7 @@ def channel_mesh(n_chips: int, n_banks: int,
 
 
 @dataclass(frozen=True)
-class ChannelExecutor:
+class ChannelExecutor(ChipExecutor):
     """A compiled channel-replay callable plus how it partitions.
 
     ``run(states, tables)`` returns the executed (n_chips, n_banks,
@@ -230,20 +258,6 @@ class ChannelExecutor:
     different devices (2-D shard_map) or one device vmaps the whole
     stack.
     """
-
-    run: Callable
-    mesh: Optional[Mesh]
-    sharded: bool
-
-    def describe(self) -> dict:
-        """Flat summary for telemetry / benchmark artifacts."""
-        return {
-            "sharded": bool(self.sharded),
-            "devices": int(self.mesh.devices.size) if self.mesh is not None
-            else 1,
-            "axes": list(self.mesh.axis_names) if self.mesh is not None
-            else [],
-        }
 
 
 def make_channel_executor(
@@ -263,7 +277,7 @@ def make_channel_executor(
     """
     if use_shard_map is False:
         _note_executor("channel", None, False)
-        return ChannelExecutor(channel_batched_interpreter(), None, False)
+        return ChannelExecutor(channel_batched_interpreter(), None)
     if mesh is None:
         mesh = channel_mesh(n_chips, n_banks)
     has_axes = mesh is not None and {"channel", "data"} <= set(
@@ -279,19 +293,18 @@ def make_channel_executor(
                 f"mesh fits n_chips={n_chips} × n_banks={n_banks} "
                 f"(devices={jax.device_count()})")
         _note_executor("channel", mesh, False)
-        return ChannelExecutor(channel_batched_interpreter(), mesh, False)
+        return ChannelExecutor(channel_batched_interpreter(), mesh)
     _note_executor("channel", mesh, True)
-    return ChannelExecutor(_sharded_channel_executor(mesh), mesh, True)
+    return ChannelExecutor(_sharded_channel_executor(mesh), mesh, CHIP_SPEC)
 
 
 @functools.lru_cache(maxsize=None)
 def _sharded_channel_executor(mesh: Mesh) -> Callable:
     """One jitted 2-D shard_map executor per mesh — every channel on the
     same mesh shares it, exactly like the chip-level executor cache."""
-    chip_spec = P("channel", "data", None, None, None)
     return jax.jit(jax.shard_map(
         channel_replay, mesh=mesh,
-        in_specs=(chip_spec, chip_spec), out_specs=chip_spec,
+        in_specs=(CHIP_SPEC, CHIP_SPEC), out_specs=CHIP_SPEC,
         check_vma=False))
 
 
@@ -308,8 +321,7 @@ def make_faulty_channel_executor(
     the chip/bank slabs."""
     if use_shard_map is False:
         _note_executor("channel.faulty", None, False)
-        return ChannelExecutor(
-            faulty_channel_batched_interpreter(), None, False)
+        return ChannelExecutor(faulty_channel_batched_interpreter(), None)
     if mesh is None:
         mesh = channel_mesh(n_chips, n_banks)
     has_axes = mesh is not None and {"channel", "data"} <= set(
@@ -325,21 +337,20 @@ def make_faulty_channel_executor(
                 f"mesh fits n_chips={n_chips} × n_banks={n_banks} "
                 f"(devices={jax.device_count()})")
         _note_executor("channel.faulty", mesh, False)
-        return ChannelExecutor(
-            faulty_channel_batched_interpreter(), mesh, False)
+        return ChannelExecutor(faulty_channel_batched_interpreter(), mesh)
     _note_executor("channel.faulty", mesh, True)
-    return ChannelExecutor(_sharded_faulty_channel_executor(mesh), mesh, True)
+    return ChannelExecutor(_sharded_faulty_channel_executor(mesh), mesh,
+                           CHIP_SPEC)
 
 
 @functools.lru_cache(maxsize=None)
 def _sharded_faulty_channel_executor(mesh: Mesh) -> Callable:
-    chip_spec = P("channel", "data", None, None, None)
     unit2 = P("channel", "data", None, None)   # keys / stuck masks
     unit1 = P("channel", "data", None)         # dead flags / flip counts
     return jax.jit(jax.shard_map(
         faulty_channel_replay, mesh=mesh,
-        in_specs=(chip_spec, chip_spec, unit2, unit2, unit2, unit1, P()),
-        out_specs=(chip_spec, unit1),
+        in_specs=(CHIP_SPEC, CHIP_SPEC, unit2, unit2, unit2, unit1, P()),
+        out_specs=(CHIP_SPEC, unit1),
         check_vma=False))
 
 
@@ -401,7 +412,7 @@ def make_rank_executor(
     """
     if use_shard_map is False:
         _note_executor("rank", None, False)
-        return ChannelExecutor(rank_batched_interpreter(), None, False)
+        return ChannelExecutor(rank_batched_interpreter(), None)
     if mesh is None:
         mesh = rank_mesh(n_channels, n_chips, n_banks)
     has_axes = mesh is not None and {"rank", "channel", "data"} <= set(
@@ -419,17 +430,16 @@ def make_rank_executor(
                 f"× n_chips={n_chips} × n_banks={n_banks} "
                 f"(devices={jax.device_count()})")
         _note_executor("rank", mesh, False)
-        return ChannelExecutor(rank_batched_interpreter(), mesh, False)
+        return ChannelExecutor(rank_batched_interpreter(), mesh)
     _note_executor("rank", mesh, True)
-    return ChannelExecutor(_sharded_rank_executor(mesh), mesh, True)
+    return ChannelExecutor(_sharded_rank_executor(mesh), mesh, CHANNEL_SPEC)
 
 
 @functools.lru_cache(maxsize=None)
 def _sharded_rank_executor(mesh: Mesh) -> Callable:
     """One jitted 3-D shard_map executor per mesh — every rank on the
     same mesh shares it, exactly like the channel-level executor cache."""
-    channel_spec = P("rank", "channel", "data", None, None, None)
     return jax.jit(jax.shard_map(
         rank_replay, mesh=mesh,
-        in_specs=(channel_spec, channel_spec), out_specs=channel_spec,
+        in_specs=(CHANNEL_SPEC, CHANNEL_SPEC), out_specs=CHANNEL_SPEC,
         check_vma=False))

@@ -348,3 +348,125 @@ def test_sharded_executor_forced_devices_subprocess():
                          timeout=600)
     assert out.returncode == 0, out.stdout + out.stderr
     assert "SHARDED_CHIP_OK 4" in out.stdout
+
+
+# --- placement of round state and tables -----------------------------------
+
+_SHARDED_Q6 = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import dataclasses
+    import numpy as np
+    from jax.sharding import NamedSharding
+    from repro.apps.tpch import q6_queue
+    from repro.core.bank import flatten_result
+    from repro.core.chip import SimdramChip
+    from repro.core.control_unit import TABLE_CACHE
+    from repro.distributed.pum import BANK_SPEC
+
+    def recording(chip):
+        seen, plain = [], chip.executor
+        def run(states, tables):
+            seen.append((states, tables))
+            return plain.run(states, tables)
+        chip.executor = dataclasses.replace(plain, run=run)
+        return seen
+
+    queue, finish = q6_queue(16 * 48, 16, seed=3)
+    TABLE_CACHE.clear()
+    chip = SimdramChip(n_banks=16, n_subarrays=1, use_shard_map=True)
+    mesh = chip.executor.mesh
+    assert mesh.shape["data"] == 4
+    banks = NamedSharding(mesh, BANK_SPEC)
+    seen = recording(chip)
+    got = chip.dispatch(queue)
+    finish(got)
+    misses = TABLE_CACHE.misses
+    assert seen and 0 < misses <= len(seen)
+    for states, tables in seen:
+        for arr in (states, tables):
+            assert arr.sharding.is_equivalent_to(banks, arr.ndim)
+            assert len(arr.sharding.device_set) == 4
+            shards = arr.addressable_shards
+            assert sorted(s.index[0].start for s in shards) == [0, 4, 8, 12]
+            assert all(s.data.shape[0] == 4 for s in shards)
+
+    twin = SimdramChip(n_banks=16, n_subarrays=1, use_shard_map=False)
+    twin_seen = recording(twin)
+    want = twin.dispatch(queue)
+    for a, b in zip(got, want):
+        for x, y in zip(flatten_result(a), flatten_result(b)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    for states, tables in twin_seen:
+        assert len(states.sharding.device_set) == 1
+        assert len(tables.sharding.device_set) == 1
+    # the same round compositions, cached once per sharding
+    assert TABLE_CACHE.misses == 2 * misses
+    assert TABLE_CACHE.stats()["entries"] == 2 * misses
+    chip.dispatch(queue)
+    assert TABLE_CACHE.misses == 2 * misses
+    print("PLACED_OK", len(seen))
+""")
+
+
+def test_sharded_round_state_and_tables_land_on_the_bank_sharding():
+    """On four devices a Q6 queue's every round reaches the executor as
+    four bank slabs, one on each device, and so does its cached table
+    stack; the answers are bit-exact against the one-device twin, whose
+    table entries the cache keeps apart."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = SRC
+    out = subprocess.run([sys.executable, "-c", _SHARDED_Q6],
+                         capture_output=True, text=True, env=env,
+                         timeout=600)
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert "PLACED_OK" in out.stdout
+
+
+def test_one_device_round_state_is_a_single_device_array():
+    """Without a mesh the executor takes each round whole, on the
+    default device, as ``jnp.asarray`` puts it there."""
+    import dataclasses
+
+    chip = SimdramChip(n_banks=4, n_subarrays=2, use_shard_map=False)
+    seen, plain = [], chip.executor
+
+    def run(states, tables):
+        seen.append((states, tables))
+        return plain.run(states, tables)
+
+    chip.executor = dataclasses.replace(plain, run=run)
+    rng = np.random.default_rng(11)
+    queue = [_rand_instr(rng, op, 8) for op in ("addition", "greater")]
+    _assert_same(chip.dispatch(queue), sequential_dispatch(queue, 4, 2)[0])
+    assert seen
+    default = jax.devices()[0]
+    for states, tables in seen:
+        assert states.sharding.device_set == {default}
+        assert tables.sharding.device_set == {default}
+    host = np.arange(24, dtype=np.uint32).reshape(2, 3, 4)
+    placed = chip.executor.place(host)
+    assert placed.sharding == jax.numpy.asarray(host).sharding
+    np.testing.assert_array_equal(np.asarray(placed), host)
+
+
+def test_table_cache_keeps_one_entry_per_sharding():
+    from jax.sharding import SingleDeviceSharding
+
+    from repro.core.control_unit import TableCache
+
+    cache, built = TableCache(), []
+
+    def build():
+        built.append(1)
+        return np.ones((2, 3), np.int32)
+
+    key = ("chip", 2, 1, 3, ())
+    one = SingleDeviceSharding(jax.devices()[0])
+    a = cache.get(key, build)
+    b = cache.get(key, build, one)
+    assert cache.get(key, build) is a
+    assert cache.get(key, build, one) is b
+    assert len(built) == 2 and cache.stats()["entries"] == 2
+    assert (cache.misses, cache.hits) == (2, 2)
+    assert b.sharding == one

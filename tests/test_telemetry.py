@@ -13,7 +13,11 @@ schema gate CI runs via ``scripts/check_trace.py``).
 import dataclasses
 import importlib.util
 import json
+import os
 import pathlib
+import subprocess
+import sys
+import textwrap
 
 import numpy as np
 import pytest
@@ -601,3 +605,57 @@ def test_traced_chip_dispatch_is_bit_identical_and_compiles_nothing():
     assert dict(trace_counts()) == counts
     assert _exact(r_traced, r_plain)
     assert traced.chip().stats.latency_s == plain.chip().stats.latency_s
+
+
+_TRANSFER_SPANS = textwrap.dedent("""
+    import json
+    import numpy as np
+    from repro import obs
+    from repro.core.bank import BbopInstr, Ref
+    from repro.core.chip import SimdramChip
+    from repro.core.control_unit import TABLE_CACHE
+
+    rng = np.random.default_rng(0)
+    a, b = (rng.integers(0, 256, 256).astype(np.uint64) for _ in range(2))
+    queue = [BbopInstr("addition", (a, b), 8),
+             BbopInstr("multiplication", (Ref(0), b), 8),
+             BbopInstr("greater", (a, b), 8)] * 4
+    TABLE_CACHE.clear()
+    chip = SimdramChip(n_banks=4, n_subarrays=2)
+    with obs.enabled() as tr:
+        chip.dispatch(queue)
+        root = tr.roots[-1]
+    print(json.dumps({name: [sp.attrs for sp in root.find(name)]
+                      for name in ("chip.h2d", "chip.harvest.fetch",
+                                   "table_cache.miss")}))
+""")
+
+
+def _transfer_spans(devices):
+    """The transfer spans' attributes of a chip of four banks on a host
+    of ``devices`` CPU devices, from a fresh process."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(REPO / "src")
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    out = subprocess.run([sys.executable, "-c", _TRANSFER_SPANS],
+                         capture_output=True, text=True, env=env,
+                         timeout=600)
+    assert out.returncode == 0, out.stdout + out.stderr
+    return json.loads(out.stdout.splitlines()[-1])
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_transfer_spans_count_the_devices_a_round_spans(devices):
+    """``chip.h2d`` gives the devices the round's state lands on and the
+    bytes each receives, ``bytes`` staying the whole; the copy back and
+    each table miss give their devices too."""
+    attrs = _transfer_spans(devices)
+    h2d = attrs["chip.h2d"]
+    assert h2d
+    for a in h2d:
+        assert a["devices"] == devices
+        assert a["devices"] * a["shard_bytes"] == a["bytes"]
+    assert [a["devices"] for a in attrs["chip.harvest.fetch"]] == \
+        [devices] * len(attrs["chip.harvest.fetch"])
+    assert attrs["table_cache.miss"]
+    assert all(a["devices"] == devices for a in attrs["table_cache.miss"])
