@@ -240,8 +240,7 @@ class VerticalOperand:
             planes = np.asarray(kops.h2v(jnp.asarray(vals), n_bits))
         else:
             from .subarray import pack_bits
-            planes = pack_bits(vals.astype(np.uint64), n_bits,
-                               _round_up(max(lanes, 1), 32))
+            planes = pack_bits(vals, n_bits, _round_up(max(lanes, 1), 32))
         return cls(planes, lanes)
 
     def to_values(self, signed: bool = False) -> np.ndarray:
@@ -305,6 +304,14 @@ class _Slot:
     spec: object
     uprog: object
     lanes: int
+
+
+def horizontal_planes(queue, entries: Sequence[_Slot]) -> int:
+    """Operand planes packing ``entries`` converts from horizontal
+    values: ``Ref`` and ``VerticalOperand`` operands arrive vertical."""
+    return sum(len(e.uprog.in_rows[k]) for e in entries
+               for k, o in enumerate(queue[e.qi].operands)
+               if not isinstance(o, (Ref, VerticalOperand)))
 
 
 @dataclass(frozen=True)
@@ -799,7 +806,8 @@ class Bank:
             states, tables, entries = self._pack_wave(
                 queue, wave, lanes, planes_cache)
             if sp_pack is not None:
-                tr.end(sp_pack, slots=len(entries))
+                tr.end(sp_pack, slots=len(entries),
+                       planes=horizontal_planes(queue, entries))
             self.stats.pack_wall_s += time.perf_counter() - t_pack
             sp_replay = (tr.begin("bank.replay", cat="replay")
                          if tr is not None else None)

@@ -46,7 +46,7 @@ import numpy as np
 
 from .isa import DispatchGuard, check_cancel
 from .bank import (Bank, BankStats, BbopInstr, Ref, _Slot,
-                   _build_stacked_tables, plan_queue)
+                   _build_stacked_tables, horizontal_planes, plan_queue)
 from .control_unit import CMD_WIDTH, TABLE_CACHE
 from .costmodel import instr_cost_s
 from .telemetry import active_tracer
@@ -439,7 +439,8 @@ class SimdramChip:
                 queue, wave, lanes, planes_cache,
                 n_rows=n_rows, n_cmds=n_cmds, cols=cols, with_tables=False)
             if sp is not None:
-                tr.end(sp, slots=len(entries))
+                tr.end(sp, slots=len(entries),
+                       planes=horizontal_planes(queue, entries))
             self.stats.transpositions_skipped += (
                 bank.stats.transpositions_skipped - skips0)
             self.stats.transpose_s_saved += (

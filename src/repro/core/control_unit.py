@@ -69,10 +69,8 @@ def load_state(
     for op_idx, rows in enumerate(uprog.in_rows):
         if operands[op_idx] is None:
             continue
-        planes = pack_bits(
-            np.asarray(operands[op_idx]).astype(np.uint64), len(rows),
-            n_columns)
-        state[list(rows)] = planes
+        state[list(rows)] = pack_bits(
+            np.asarray(operands[op_idx]), len(rows), n_columns)
     return state
 
 

@@ -83,31 +83,48 @@ class Subarray:
 # vertical-layout helpers (transposition-unit functionality, numpy side)
 # ---------------------------------------------------------------------------
 
-def pack_bits(values: np.ndarray, n_bits: int, n_columns: int) -> np.ndarray:
-    """Horizontal -> vertical: (lanes,) uints -> (n_bits, n_words) uint32.
+# Delta swaps that transpose an 8x8 bit matrix held in a uint64 (bit
+# 8r + c <-> bit 8c + r): swap the 2x2 blocks' off-diagonal bits, then
+# those of the 4x4 blocks, then of the two 4x4 halves.
+_TRANSPOSE8 = tuple((np.uint64(shift), np.uint64(mask)) for shift, mask in
+                    ((7, 0x00AA00AA00AA00AA), (14, 0x0000CCCC0000CCCC),
+                     (28, 0x00000000F0F0F0F0)))
 
-    Vectorized over bit positions — one shift broadcast and ONE packbits
-    call instead of a per-bit Python loop (this is the host side of the
-    transposition unit; it sits on the wave packer's critical path)."""
+
+def pack_bits(values: np.ndarray, n_bits: int, n_columns: int) -> np.ndarray:
+    """Horizontal -> vertical: (lanes,) integers -> (n_bits, n_words) uint32.
+
+    Plane *p* holds bit *p* of each value's two's complement, lane *c* at
+    bit ``c % 32`` of word ``c // 32``; columns past ``lanes`` are zero.
+    A bit-matrix transpose on the values' little-endian bytes, the
+    inverse of :func:`unpack_bits`: byte *g* of 8 consecutive lanes,
+    gathered into one uint64 and transposed as an 8×8 bit matrix, is
+    byte ``lane // 8`` of planes 8g…8g+7.  Only the ``ceil(n_bits / 8)``
+    byte columns that hold a plane are read, and the values are widened
+    (sign-extending) only where their dtype is narrower than that.
+    Temporaries hold about one byte per lane per byte column."""
     lanes = values.shape[0]
     assert lanes <= n_columns
-    if n_bits == 0:
-        return np.zeros((0, n_columns // 32), dtype=np.uint32)
-    if lanes == 0:
-        return np.zeros((n_bits, n_columns // 32), dtype=np.uint32)
-    # bit extraction via unpackbits on the little-endian byte view — a
-    # single C pass, much faster than 64-bit shift broadcasting (only
-    # the low n_bits matter, so ≤32-bit packs narrow to uint32 first)
-    if n_bits <= 32:
-        by = values.astype(np.uint32).view(np.uint8).reshape(lanes, 4)
-    else:
-        by = values.astype(np.uint64).view(np.uint8).reshape(lanes, 8)
-    bits = np.unpackbits(by, axis=1, bitorder="little")
-    padded = np.zeros((n_bits, n_columns), dtype=np.uint8)
-    padded[:, :lanes] = bits[:, :n_bits].T
-    return np.packbits(
-        padded.reshape(-1), bitorder="little"
-    ).view(np.uint32).reshape(n_bits, -1)
+    nb = -(-n_bits // 8)                # byte columns that hold a plane
+    groups = -(-lanes // 8)             # plane bytes that hold a lane
+    if values.dtype.itemsize < nb:
+        values = values.astype(np.uint64)
+    by = np.ascontiguousarray(values).view(np.uint8).reshape(
+        lanes, values.dtype.itemsize)
+    cols = np.zeros((nb, groups * 8), dtype=np.uint8)
+    cols[:, :lanes] = by[:, :nb].T
+    x = cols.view(np.uint64)            # (nb, groups): 8 lanes' byte g
+    t = np.empty_like(x)
+    for shift, mask in _TRANSPOSE8:
+        np.right_shift(x, shift, out=t)
+        t ^= x
+        t &= mask
+        x ^= t
+        t <<= shift
+        x ^= t
+    out = np.zeros((nb, 8, n_columns // 8), dtype=np.uint8)
+    out[:, :, :groups] = cols.reshape(nb, groups, 8).transpose(0, 2, 1)
+    return out.reshape(nb * 8, n_columns // 8)[:n_bits].view(np.uint32)
 
 
 # _SPREAD[i, b]: byte b's bit k moved to bit i of byte k.  One plane

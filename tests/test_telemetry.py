@@ -574,6 +574,28 @@ def test_harvest_out_counts_only_the_planes_it_converts():
         cached_table("greater", 8, "mig")[0].out_bits)
 
 
+def test_pack_wave_counts_only_the_horizontal_planes_it_converts():
+    from repro.core.bank import VerticalOperand, cached_table
+
+    rng = np.random.default_rng(3)
+    a, b = (rng.integers(0, 256, 128).astype(U) for _ in range(2))
+    queue = _queue(lanes=128) + [
+        BbopInstr("subtraction", (VerticalOperand.from_values(a, 8), b), 8)]
+    dev = _chip_device()
+    with obs.enabled() as tr:
+        dev.dispatch(queue)
+        root = tr.roots[-1]
+    spans = root.find("bank.pack_wave")
+    assert spans
+    # Ref(0) into the multiplication and the vertical operand of the
+    # subtraction arrive as planes: 2 + 1 + 2 + 1 horizontal 8-bit operands
+    assert sum(sp.attrs["planes"] for sp in spans) == sum(
+        w for ins in queue
+        for w, o in zip(cached_table(ins.op, ins.n_bits, "mig")[0]
+                        .operand_bits, ins.operands)
+        if not isinstance(o, (Ref, VerticalOperand))) == 48
+
+
 def test_chip_spans_reach_the_profiler_host_plane(tmp_path):
     import jax
     from jax.profiler import ProfileData
